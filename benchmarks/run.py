@@ -43,6 +43,10 @@ def main() -> None:
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import kernels_bench, paper, roofline_table, slo_bench
 
     n = 10000 if args.full else (600 if args.smoke else 4000)

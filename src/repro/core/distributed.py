@@ -27,22 +27,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
 
-# jax.shard_map is the post-0.4.x spelling; fall back to the experimental one
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _axis_size(name: str) -> int:
-    """Static mapped-axis size (jax.lax.axis_size is post-0.4.x; on 0.4.x
-    jax.core.axis_frame returns the size directly)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    frame = jax.core.axis_frame(name)
-    return frame if isinstance(frame, int) else frame.size
-
 INF = jnp.inf
 AXIS = "place"
+
+
+def vary_like(x: jnp.ndarray, ref: jnp.ndarray) -> jnp.ndarray:
+    """Mark ``x`` as varying over every mesh axis ``ref`` varies over.
+
+    A scan carry that mixes with collective-derived data must carry the same
+    varying-axis set as that data: ``place`` alone inside the engine, but
+    ``batch`` and ``place`` when the engine runs per instance on a
+    (batch × place) mesh (core/sharded_batch.py)."""
+    return jax.lax.pcast(x, tuple(jax.typeof(ref).vma), to="varying")
 
 
 class ShardState(NamedTuple):
@@ -84,7 +80,7 @@ def phase(st: ShardState, k: int, k_buf: int) -> Tuple[ShardState, jnp.ndarray, 
     (state, popped_id i32[], popped_prio f32[]) — one pop per place (-1 if
     none visible)."""
     p = jax.lax.axis_index(AXIS)
-    nplaces = _axis_size(AXIS)
+    nplaces = jax.lax.axis_size(AXIS)
 
     # ---- publish: if >= k unpublished, move up to k_buf into the buffer ----
     must_pub = st.unpub >= k
@@ -146,11 +142,7 @@ def phase(st: ShardState, k: int, k_buf: int) -> Tuple[ShardState, jnp.ndarray, 
         claimed = claimed.at[pl].set(pick)
         return claimed, pick
 
-    claimed0 = jnp.full((nplaces,), -1, jnp.int32)
-    # vma bookkeeping: the carry mixes with all_gather-derived (varying) data
-    # (post-0.4.x only; 0.4.x shard_map has no varying-axis tracking)
-    if hasattr(jax.lax, "pcast"):
-        claimed0 = jax.lax.pcast(claimed0, (AXIS,), to="varying")
+    claimed0 = vary_like(jnp.full((nplaces,), -1, jnp.int32), all_ids)
     claimed, picks = jax.lax.scan(claim, claimed0, jnp.arange(nplaces))
     my_pick = picks[p]
     popped_id = my_pick
@@ -175,7 +167,7 @@ def make_engine(mesh: Mesh, m_loc: int, g_cap: int, k: int, k_buf: int):
     where pushes = (prio f32[P, n], id i32[P, n]) per-place new tasks."""
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(PS(AXIS), (PS(AXIS), PS(AXIS))),
         out_specs=(PS(AXIS), PS(AXIS), PS(AXIS)),
     )
@@ -197,8 +189,8 @@ def make_engine(mesh: Mesh, m_loc: int, g_cap: int, k: int, k_buf: int):
 
 def selftest(nplaces: int) -> None:  # pragma: no cover - exercised via subprocess
     import numpy as np
-    from repro.launch.mesh import axis_types_kwargs
-    mesh = jax.make_mesh((nplaces,), (AXIS,), **axis_types_kwargs(1))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((nplaces,), (AXIS,))
     m_loc, g_cap, k, k_buf = 64, 512, 3, 8
     engine = make_engine(mesh, m_loc, g_cap, k, k_buf)
     state = jax.tree.map(

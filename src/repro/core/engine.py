@@ -198,17 +198,20 @@ def _phase_chunk_sharded(mesh, chunk, num_places, k, policy, arbitration,
     instances are independent, see core/sharded_batch.py)."""
     from jax.sharding import PartitionSpec as PS
 
-    from repro.core.sharded_batch import BATCH_AXIS, _shard_map
+    from repro.launch.mesh import BATCH_AXIS
 
     local = functools.partial(
         _phase_chunk_impl, chunk=chunk, num_places=num_places, k=k,
         policy=policy, arbitration=arbitration, topk_backend=topk_backend,
     )
-    f = _shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(BATCH_AXIS),) * 4,
         # stats leaves are [chunk, G]: batch axis is dim 1 there
         out_specs=(PS(BATCH_AXIS), PS(None, BATCH_AXIS), PS(BATCH_AXIS)),
+        # no collectives to check, and the Pallas stage-1 kernel is traced
+        # without varying-axis types
+        check_vma=False,
     )
     return jax.jit(f)
 

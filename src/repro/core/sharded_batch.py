@@ -35,12 +35,8 @@ from jax.sharding import Mesh, PartitionSpec as PS
 
 from repro.core import batched
 from repro.core import kpriority as kp
+from repro.core.distributed import vary_like
 from repro.launch.mesh import BATCH_AXIS
-
-# jax.shard_map is the post-0.4.x spelling; fall back to the experimental one
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def batch_axis_size(mesh: Mesh) -> int:
@@ -100,10 +96,13 @@ def _sharded_phase_pop_fn(
             block_size=block_size,
         )
 
-    f = _shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(PS(BATCH_AXIS), PS(BATCH_AXIS)),
         out_specs=(PS(BATCH_AXIS), PS(BATCH_AXIS)),
+        # no collectives to check, and the Pallas stage-1 kernel is traced
+        # without varying-axis types
+        check_vma=False,
     )
     return jax.jit(f)
 
@@ -305,7 +304,7 @@ def make_engine_batched(mesh: Mesh, m_loc: int, g_cap: int, k: int, k_buf: int):
     spec = PS(BATCH_AXIS, dist.AXIS)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, (spec, spec)),
         out_specs=(spec, spec, spec),
     )
@@ -361,7 +360,7 @@ def make_pod_engine(
     spec = PS(POD_AXIS)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, (spec, spec)),
         out_specs=(spec, spec, spec, spec, spec, spec),
     )
@@ -383,11 +382,7 @@ def make_pod_engine(
         pays_u = jax.lax.all_gather(pay_u, POD_AXIS)
 
         n = heads_p.shape[0]
-        claimed0 = jnp.zeros((n,), bool)
-        # vma bookkeeping: the scan carry mixes with all_gather-derived
-        # (varying) headers (post-0.4.x shard_map only, as in distributed.py)
-        if hasattr(jax.lax, "pcast"):
-            claimed0 = jax.lax.pcast(claimed0, (POD_AXIS,), to="varying")
+        claimed0 = vary_like(jnp.zeros((n,), bool), heads_p)
         fire, victim = kp.pod_steal_plan(
             heads_p, heads_u, hases, fronts_p, fronts_v,
             margin=margin, claimed0=claimed0,
@@ -497,8 +492,9 @@ def _selftest_sssp_bit_identity(graphs: int):  # pragma: no cover
     print(f"SHARDED_SSSP_OK G={graphs}")
 
 
-def _selftest_batch_place(nbatch: int, nplace: int):  # pragma: no cover
-    """Exactly-once per instance on the composed (batch × place) engine."""
+def selftest_batch_place(nbatch: int, nplace: int):  # pragma: no cover
+    """Exactly-once per instance on the composed (batch × place) engine,
+    on the first ``nbatch × nplace`` devices."""
     import numpy as np
 
     from repro.core import distributed as dist
@@ -573,17 +569,20 @@ def _selftest_serve_mesh():  # pragma: no cover
     print(f"SERVE_MESH_OK slots={len(jax.devices())}")
 
 
-def _selftest_pod(seed: int = 7, phases: int = 90) -> None:  # pragma: no cover
+def selftest_pod(mesh: Mesh | None = None, seed: int = 7,
+                 phases: int = 90) -> None:  # pragma: no cover
     """Cross-pod steal plane == HostPodQueues replay, bit-for-bit: steal
     decisions (fire + victim), per-pod pop streams, and the full sorted
     (prio, uid, block) state records after every phase, over a randomized
-    uneven-push trace on the multi-pod test mesh; exactly-once at drain."""
+    uneven-push trace on ``mesh`` (default: the 8-device multi-pod test
+    mesh); exactly-once at drain."""
     import numpy as np
 
     from repro.core.host_queue import HostPodQueues
     from repro.launch.mesh import make_test_production_batch_mesh
 
-    mesh = make_test_production_batch_mesh(multi_pod=True)
+    if mesh is None:
+        mesh = make_test_production_batch_mesh(multi_pod=True)
     npods = mesh.shape[POD_AXIS]
     m, k, n_push, margin = 128, 3, 4, 0.25
     block_cap = k + n_push
@@ -651,7 +650,7 @@ def selftest() -> None:  # pragma: no cover - exercised via subprocess
     _selftest_sssp_bit_identity(d)
     _selftest_sssp_bit_identity(d - 3)        # padded SSSP batch
     if d >= 8:
-        _selftest_batch_place(2, 4)
+        selftest_batch_place(2, 4)
     _selftest_serve_mesh()
     print(f"SHARDED_OK devices={d}")
 
@@ -660,6 +659,6 @@ if __name__ == "__main__":
     import sys
 
     if "--selftest-pod" in sys.argv:
-        _selftest_pod()
+        selftest_pod()
     elif "--selftest" in sys.argv:
         selftest()

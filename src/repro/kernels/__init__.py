@@ -1,3 +1,12 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the scheduler (``relaxed_topk``) and the model
+stack (``flash_attention``), their jitted wrappers (``ops``) and pure-jnp
+oracles (``ref``)."""
+import jax
+
+
+def default_interpret() -> bool:
+    """Backend-derived default for every kernel's ``interpret=``: compiled
+    Pallas on TPU (the kernels are written for Mosaic and have never been
+    validated under a Triton lowering), interpret mode everywhere else
+    (CPU/GPU; interpret is the validation vehicle, DESIGN.md §7.2)."""
+    return jax.default_backend() != "tpu"

@@ -23,6 +23,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 NEG_INF = float("-inf")
 
@@ -95,8 +98,14 @@ def flash_attention(
     sm_scale: Optional[float] = None,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    """Causal/windowed GQA attention, ``q`` [B, H, Sq, D] over ``k``/``v``
+    [B, Hkv, Skv, D]. ``interpret=None`` resolves through
+    :func:`repro.kernels.default_interpret`: compiled on TPU, interpret
+    mode elsewhere."""
+    if interpret is None:
+        interpret = default_interpret()
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     assert h % hkv == 0, "query heads must be a multiple of kv heads"
@@ -143,21 +152,11 @@ def flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[
-            _scratch(block_q, d),
-            _scratch(block_q, 128),
-            _scratch(block_q, 128),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)
     return out[:, :, :sq, :]
 
-
-def _scratch(rows: int, cols: int):
-    from jax.experimental import pallas as pl  # local import for clarity
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM((rows, cols), jnp.float32)
-    except Exception:  # pragma: no cover - CPU-only fallback
-        return pl.VMEM((rows, cols), jnp.float32)

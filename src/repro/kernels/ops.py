@@ -1,11 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``relaxed_topk``'s ``interpret`` defaults to None, which resolves through the
-backend logic (compiled on TPU, interpret elsewhere — see kernels/
-relaxed_topk.py). ``flash_attention`` still defaults to interpret=True (this
-container validates on CPU); pass ``interpret=False`` on real TPU. The model
-stack selects kernels via ``ModelConfig.attention_impl`` — the dry-run/
-roofline path always uses the pure-XLA implementations (see DESIGN.md §7.2).
+Every wrapper's ``interpret`` defaults to None, which resolves through
+:func:`repro.kernels.default_interpret`: compiled on TPU, interpret mode
+elsewhere. No model path calls ``flash_attention``: the models attend with
+the pure-XLA ``models.attention.blockwise_attention`` (DESIGN.md §7.2).
 """
 from __future__ import annotations
 
@@ -64,7 +62,7 @@ def flash_attention(
     sm_scale: Optional[float] = None,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     return _flash(
         q, k, v,

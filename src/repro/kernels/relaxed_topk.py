@@ -28,48 +28,53 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 NEG_INF = float("-inf")
 
 
-def _default_interpret() -> bool:
-    """Backend-derived default for ``interpret=`` (mirrors ``topk_select``'s
-    backend logic exactly): compiled Pallas on TPU only — the kernel is
-    written for Mosaic (lane-aligned reshapes, scalar stores) and has never
-    been validated under a Triton lowering — interpret mode everywhere else
-    (CPU/GPU; interpret is the validation vehicle, DESIGN.md §7.2)."""
-    return jax.default_backend() != "tpu"
-
-
 def _block_topc_kernel(x_ref, vals_ref, idx_ref, *, c: int, block_size: int):
-    """Extract the top-c values (+global indices) of one block.
+    """Top-c values (+global indices) of one (instance, block) grid cell.
 
-    The block is viewed as (block_size // 128, 128) so both reductions and the
-    iota are 2D (TPU-legal). c sequential max+mask rounds; each round is a full
-    VPU reduction — O(c · block_size) work, no sort network needed.
+    Grid axis 0 is the instance, axis 1 the block. The block arrives as a
+    (block_size // 128, 128) tile, so every reduction and iota is 2-D. Each
+    of the c rounds takes the max, then the lowest not-yet-taken flat index
+    attaining it (so ties, -inf padding included, resolve to distinct
+    indices in ascending order, exactly as ``lax.top_k``), and masks that
+    element out. O(c · block_size) VPU work, no sort network. Results are
+    gathered in one lane vector per output and written with a single
+    aligned store (Mosaic has no scalar stores to VMEM).
     """
-    b = pl.program_id(0)
+    j = pl.program_id(1)
     rows = block_size // 128
-    x = x_ref[...].reshape(rows, 128).astype(jnp.float32)
-    base = b * block_size
+    lanes = vals_ref.shape[-1]
+    x = x_ref[0, 0]                                           # [rows, 128]
     gidx = (
         jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
         + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
-        + base
+        + j * block_size
     )
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    taken = jnp.iinfo(jnp.int32).max
 
     def body(i, carry):
-        x, = carry
-        m = jnp.max(x)
-        # lowest flat index attaining the max (deterministic tie-break)
-        is_max = x >= m
-        cand_idx = jnp.where(is_max, gidx, jnp.iinfo(jnp.int32).max)
-        j = jnp.min(cand_idx)
-        vals_ref[0, i] = m
-        idx_ref[0, i] = j
-        x = jnp.where(gidx == j, NEG_INF, x)
-        return (x,)
+        x, g, vals, idx = carry
+        m = jnp.max(jnp.max(x, axis=1, keepdims=True), axis=0, keepdims=True)
+        cand = jnp.where(x >= m, g, taken)
+        jj = jnp.min(jnp.min(cand, axis=1, keepdims=True), axis=0,
+                     keepdims=True)                           # [1, 1]
+        hit = g == jj
+        x = jnp.where(hit, NEG_INF, x)
+        g = jnp.where(hit, taken, g)
+        vals = jnp.where(lane == i, m, vals)
+        idx = jnp.where(lane == i, jj, idx)
+        return x, g, vals, idx
 
-    jax.lax.fori_loop(0, c, body, (x,))
+    vals0 = jnp.full((1, lanes), NEG_INF, jnp.float32)
+    idx0 = jnp.full((1, lanes), -1, jnp.int32)
+    _, _, vals, idx = jax.lax.fori_loop(0, c, body, (x, gidx, vals0, idx0))
+    vals_ref[0, 0] = vals
+    idx_ref[0, 0] = idx
 
 
 def relaxed_topk(
@@ -85,81 +90,15 @@ def relaxed_topk(
     Returns (values[p], indices[p]) sorted descending. ρ = max(0, p - c).
     ``x`` is padded with -inf to a multiple of ``block_size`` (padding can
     never be selected unless p > N). ``interpret=None`` (default) resolves
-    through the backend logic (:func:`_default_interpret`): compiled on
-    TPU, interpret elsewhere — a direct caller on TPU gets the compiled
-    kernel, not silent interpret-mode Pallas.
+    through :func:`repro.kernels.default_interpret`: compiled on TPU,
+    interpret elsewhere — a direct caller on TPU gets the compiled kernel,
+    not silent interpret-mode Pallas. The B = 1 slice of
+    :func:`relaxed_topk_batched` (one kernel, no drift).
     """
-    if interpret is None:
-        interpret = _default_interpret()
-    if c is None:
-        c = p  # exact by default
-    n = x.shape[0]
-    assert block_size % 128 == 0, "block_size must be lane-aligned (128)"
-    n_pad = -n % block_size
-    xp = jnp.pad(x.astype(jnp.float32), (0, n_pad), constant_values=NEG_INF)
-    nb = xp.shape[0] // block_size
-    c_eff = min(c, block_size)
-
-    vals, idx = pl.pallas_call(
-        functools.partial(_block_topc_kernel, c=c_eff, block_size=block_size),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block_size,), lambda b: (b,))],
-        out_specs=[
-            pl.BlockSpec((1, c_eff), lambda b: (b, 0)),
-            pl.BlockSpec((1, c_eff), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, c_eff), jnp.float32),
-            jax.ShapeDtypeStruct((nb, c_eff), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp)
-
-    # exact top-p merge over the B*c candidates (tiny: B*c << N)
-    flat_v = vals.reshape(-1)
-    flat_i = idx.reshape(-1)
-    top_v, pos = jax.lax.top_k(flat_v, min(p, flat_v.shape[0]))
-    top_i = flat_i[pos]
-    if top_v.shape[0] < p:  # degenerate: fewer candidates than p
-        pad = p - top_v.shape[0]
-        top_v = jnp.pad(top_v, (0, pad), constant_values=NEG_INF)
-        top_i = jnp.pad(top_i, (0, pad), constant_values=-1)
-    return top_v, top_i
-
-
-# ---------------------------------------------------------------------------
-# natively-batched kernel: B instances × NB blocks as one 2-D grid
-# ---------------------------------------------------------------------------
-
-def _block_topc_kernel_batched(
-    x_ref, vals_ref, idx_ref, *, c: int, block_size: int
-):
-    """Per-(instance, block) top-c. Grid axis 0 is the instance, axis 1 the
-    block; the block body is identical to :func:`_block_topc_kernel` with the
-    block index taken from grid axis 1, so row b of the batched kernel is
-    bit-identical to the 1-D kernel on instance b alone."""
-    j = pl.program_id(1)
-    rows = block_size // 128
-    x = x_ref[...].reshape(rows, 128).astype(jnp.float32)
-    base = j * block_size
-    gidx = (
-        jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
-        + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
-        + base
+    v, i = relaxed_topk_batched(
+        x[None], p, c=c, block_size=block_size, interpret=interpret
     )
-
-    def body(i, carry):
-        x, = carry
-        m = jnp.max(x)
-        is_max = x >= m
-        cand_idx = jnp.where(is_max, gidx, jnp.iinfo(jnp.int32).max)
-        jj = jnp.min(cand_idx)
-        vals_ref[0, 0, i] = m
-        idx_ref[0, 0, i] = jj
-        x = jnp.where(gidx == jj, NEG_INF, x)
-        return (x,)
-
-    jax.lax.fori_loop(0, c, body, (x,))
+    return v[0], i[0]
 
 
 def relaxed_topk_batched(
@@ -175,11 +114,18 @@ def relaxed_topk_batched(
     ``x`` is [B, N]; returns (values[B, p], indices[B, p]), row b bit-identical
     to ``relaxed_topk(x[b], p, ...)``. The Pallas grid is 2-D over
     (instance, block): all B instances' block-local top-c extractions run in
-    the same launch (no per-instance host-side Python, no vmap-lifted kernel),
-    then one batched exact top-p merges each row's B·c candidates.
+    the same launch (no per-instance host-side Python, no vmap-lifted
+    kernel), then one batched exact top-p merges each row's B·c candidates.
+
+    Layout (Mosaic's (8, 128) block rule): the input is viewed as
+    [B, nb, block_size // 128, 128] and each grid cell reads one whole
+    [block_size // 128, 128] tile; each cell writes its c candidates as one
+    [1, L] lane row of a [B, nb, 1, L] output, L = c rounded up to 128.
+    Both blocks equal the array's trailing two dims, so any B, nb and
+    lane-aligned block_size is legal.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     if c is None:
         c = p
     batch, n = x.shape
@@ -189,26 +135,30 @@ def relaxed_topk_batched(
         x.astype(jnp.float32), ((0, 0), (0, n_pad)), constant_values=NEG_INF
     )
     nb = xp.shape[1] // block_size
+    rows = block_size // 128
     c_eff = min(c, block_size)
+    lanes = -(-c_eff // 128) * 128
 
     vals, idx = pl.pallas_call(
-        functools.partial(
-            _block_topc_kernel_batched, c=c_eff, block_size=block_size
-        ),
+        functools.partial(_block_topc_kernel, c=c_eff, block_size=block_size),
         grid=(batch, nb),
-        in_specs=[pl.BlockSpec((1, block_size), lambda b, j: (b, j))],
+        in_specs=[
+            pl.BlockSpec((1, 1, rows, 128), lambda b, j: (b, j, 0, 0))
+        ],
         out_specs=[
-            pl.BlockSpec((1, 1, c_eff), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, c_eff), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, 1, lanes), lambda b, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, lanes), lambda b, j: (b, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((batch, nb, c_eff), jnp.float32),
-            jax.ShapeDtypeStruct((batch, nb, c_eff), jnp.int32),
+            jax.ShapeDtypeStruct((batch, nb, 1, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((batch, nb, 1, lanes), jnp.int32),
         ],
         interpret=interpret,
-    )(xp)
+    )(xp.reshape(batch, nb, rows, 128))
 
-    return _merge_topp_batched(vals, idx, p)
+    return _merge_topp_batched(
+        vals[:, :, 0, :c_eff], idx[:, :, 0, :c_eff], p
+    )
 
 
 def _merge_topp_batched(

@@ -4,25 +4,23 @@ Defined as FUNCTIONS (not module constants) so importing this module never
 touches jax device state."""
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
-def axis_types_kwargs(n_axes: int) -> Dict[str, object]:
-    """``axis_types=`` kwargs for ``jax.make_mesh`` when this jax supports
-    them (jax.sharding.AxisType landed after 0.4.x; Auto is the 0.4.x
-    default, so omitting the kwarg is behaviour-preserving there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated
+    shardings), the mode all of this repo's meshes are written for."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def logical_rules(multi_pod: bool = False) -> Dict[str, object]:
@@ -43,7 +41,7 @@ def logical_rules(multi_pod: bool = False) -> Dict[str, object]:
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device unit tests (host platform)."""
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 BATCH_AXIS = "batch"
@@ -54,7 +52,7 @@ def make_batch_mesh(num_devices: int | None = None):
     instances spread across D devices with zero cross-device traffic between
     instances (core/sharded_batch.py). Defaults to all local devices."""
     d = len(jax.devices()) if num_devices is None else num_devices
-    return jax.make_mesh((d,), (BATCH_AXIS,), **axis_types_kwargs(1))
+    return make_mesh((d,), (BATCH_AXIS,))
 
 
 def make_production_batch_mesh(
@@ -72,7 +70,7 @@ def make_production_batch_mesh(
     shape = (batch, 2, data, model) if multi_pod else (batch, data, model)
     axes = ((BATCH_AXIS, "pod", "data", "model") if multi_pod
             else (BATCH_AXIS, "data", "model"))
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_test_production_batch_mesh(*, multi_pod: bool = False):
@@ -101,6 +99,4 @@ def make_batch_place_mesh(batch: int, place: int):
     instance stay inside its ``place`` sub-mesh."""
     from repro.core.distributed import AXIS as PLACE_AXIS
 
-    return jax.make_mesh(
-        (batch, place), (BATCH_AXIS, PLACE_AXIS), **axis_types_kwargs(2)
-    )
+    return make_mesh((batch, place), (BATCH_AXIS, PLACE_AXIS))

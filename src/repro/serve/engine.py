@@ -148,9 +148,14 @@ class _PlanPacker:
                 raise RuntimeError("plan packer died") from self._error
 
     def stop(self):
+        """Stop the thread and wait for it, so that no packer is still
+        unwinding when the interpreter shuts down (a daemon thread caught
+        mid-exit there aborts the process)."""
         with self._cv:
             self._stop = True
             self._cv.notify_all()
+        if threading.current_thread() is not self._thread:
+            self._thread.join(timeout=60.0)
 
 
 @dataclasses.dataclass
@@ -433,6 +438,21 @@ class ServeEngine:
             self._push_seq += 1
             req._uid = self._push_seq
             self.queue.push(frontend, qprio, req)
+
+    def wait_packed(self, timeout: float = 60.0):
+        """Block until the async packer has published every submission so
+        far into the open arrival plan, so the next step folds all of them —
+        the submit-then-step hand-off of the eager planes, which a replay
+        against the host oracle needs. A no-op unless ``step="continuous"``
+        with ``packer="thread"``."""
+        if self._packer is None:
+            return
+        deadline = time.monotonic() + timeout
+        while self._packer.backlog():
+            if time.monotonic() > deadline:
+                raise TimeoutError("plan packer failed to drain")
+            self._packer.wait_progress()
+        self._packer.check()
 
     def _drain_plans(self, timeout: float = 60.0):
         """Drain the continuous submission path onto the exact flush path:

@@ -468,13 +468,7 @@ class PlanSlot:
     def __init__(self, num_places: int, cap: int):
         self.num_places = num_places
         self.cap = cap
-        self.prio = np.full((num_places, cap), np.inf, np.float32)
-        self.slot = np.full((num_places, cap), -1, np.int32)
-        self.arrival = np.zeros((num_places, cap), np.int32)
-        self.count = np.zeros((num_places,), np.int32)
-        #: publish order, (place, pool_slot, prio, arrival) — the host-side
-        #: replay record the engine needs at fold time
-        self.entries: List[Tuple[int, int, float, int]] = []
+        self.clear()
 
     def publish(self, place: int, pool_slot: int, prio: float,
                 arrival: int) -> bool:
@@ -496,11 +490,17 @@ class PlanSlot:
         return int(self.count.sum())
 
     def clear(self):
-        self.prio.fill(np.inf)
-        self.slot.fill(-1)
-        self.arrival.fill(0)
-        self.count.fill(0)
-        self.entries.clear()
+        """Start an empty plan in FRESH arrays. Never refill in place: the
+        sealed arrays were just handed to an asynchronous upload, which may
+        still read them (JAX can use a numpy buffer without copying it)."""
+        p, c = self.num_places, self.cap
+        self.prio = np.full((p, c), np.inf, np.float32)
+        self.slot = np.full((p, c), -1, np.int32)
+        self.arrival = np.zeros((p, c), np.int32)
+        self.count = np.zeros((p,), np.int32)
+        #: publish order, (place, pool_slot, prio, arrival) — the host-side
+        #: replay record the engine needs at fold time
+        self.entries: List[Tuple[int, int, float, int]] = []
 
 
 class PlanBook:

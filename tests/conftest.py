@@ -4,10 +4,3 @@ import sys
 # NOTE: no XLA_FLAGS device-count override here — tests must see 1 device
 # (the 512-device override belongs exclusively to repro/launch/dryrun.py).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# hypothesis is optional: on clean containers the property tests run against
-# a deterministic fixed-sample shim instead of failing collection.
-sys.path.insert(0, os.path.dirname(__file__))
-from _hypothesis_fallback import install as _install_hypothesis_fallback  # noqa: E402
-
-HYPOTHESIS_IS_FALLBACK = _install_hypothesis_fallback()

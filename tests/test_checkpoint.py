@@ -73,17 +73,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
-from repro.launch.mesh import axis_types_kwargs
+from repro.launch.mesh import make_mesh
 
 d = sys.argv[1]
 t = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-mesh_a = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kwargs(2))
+mesh_a = make_mesh((2, 4), ("data", "model"))
 sh_a = {"w": NamedSharding(mesh_a, P("data", "model"))}
 t_a = jax.device_put(t["w"], sh_a["w"])
 mgr = CheckpointManager(d)
 mgr.save(1, {"w": t_a})
 # elastic: restore onto a DIFFERENT mesh shape (simulates node loss 8->4)
-mesh_b = jax.make_mesh((4, 1), ("data", "model"), **axis_types_kwargs(2))
+mesh_b = make_mesh((4, 1), ("data", "model"))
 sh_b = {"w": NamedSharding(mesh_b, P("data", "model"))}
 like = {"w": np.zeros((8, 8), np.float32)}
 r = mgr.restore_sharded(1, like, sh_b)

@@ -289,6 +289,26 @@ def test_plan_book_backpressure_and_protocol():
     s.clear()
 
 
+def test_plan_slot_clear_keeps_uploaded_arrays():
+    """A sealed plan's arrays go to an asynchronous device upload that may
+    still read them after ``clear``: clearing starts fresh arrays and never
+    refills the handed-off ones in place (an in-place refill let the upload
+    read slot -1, which the pool scatter wrapped to ``capacity - 1``)."""
+    book = PlanBook(2, 4)
+    assert book.publish(0, 10, 1.0, 0)
+    assert book.publish(1, 11, 0.5, 1)
+    sealed = book.seal()
+    handed = (sealed.prio, sealed.slot, sealed.arrival, sealed.count)
+    before = [a.copy() for a in handed]
+    sealed.clear()
+    for a, b in zip(handed, before):
+        np.testing.assert_array_equal(a, b)
+    fresh = (sealed.prio, sealed.slot, sealed.arrival, sealed.count)
+    assert all(a is not b for a, b in zip(handed, fresh))
+    assert sealed.total() == 0 and not sealed.entries
+    assert (sealed.slot == -1).all() and (sealed.prio == np.inf).all()
+
+
 def test_threaded_packer_backpressure_and_liveness():
     """The async packer under forced spills: plan rows sized below the
     burst, so publish_wait blocks until the consumer seals and entries
@@ -452,11 +472,7 @@ def test_engine_continuous_matches_host():
         for i, toks in enumerate(prompts):
             eng.submit(Request(rid=i, tokens=toks, max_new=4,
                                priority=prios[i]), frontend=i % 2)
-        if packer == "thread":
-            deadline = time.monotonic() + 60
-            while eng._packer.backlog():
-                assert time.monotonic() < deadline, "packer stalled"
-                eng._packer.wait_progress()
+        eng.wait_packed()
         done = eng.run()
         return eng.admission_log, {r.rid: r.out for r in done}
 

@@ -38,15 +38,26 @@ def test_relaxed_topk_exact_when_c_eq_p(dtype):
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 1000), p=st.integers(4, 64), c=st.integers(1, 64))
 def test_relaxed_topk_rho_property(seed, p, c):
-    """Structural ρ-relaxation: #(items better than the worst selected but
-    not selected) <= max(0, p - c)."""
-    n = 2048
+    """Structural ρ-relaxation: at most max(0, p - c) of the exact top p are
+    missed, and an item better than the worst selected one is passed over
+    only when its own block already gave up min(c, block) better items.
+
+    (Counting every unselected item above the worst selected one is not
+    bounded by p - c: with c = 1, the runner-up of a strong block can beat
+    the winners of several weak blocks.)"""
+    n, block = 2048, 256
     x = np.asarray(jax.random.normal(jax.random.PRNGKey(seed), (n,)))
-    v, i = relaxed_topk(jnp.asarray(x), p, c=c, block_size=256)
-    sel = set(int(j) for j in np.asarray(i) if j >= 0)
-    worst = float(np.asarray(v)[np.asarray(i) >= 0].min())
-    ignored = int(np.sum(x > worst)) - sum(1 for j in sel if x[j] > worst)
-    assert ignored <= max(0, p - c), (ignored, p, c)
+    v, i = relaxed_topk(jnp.asarray(x), p, c=c, block_size=block)
+    idx = np.asarray(i)
+    chosen = np.zeros(n, bool)
+    chosen[idx[idx >= 0]] = True
+    exact = np.argsort(-x, kind="stable")[:p]
+    missed = int(np.sum(~chosen[exact]))
+    assert missed <= max(0, p - c), (missed, p, c)
+    worst = float(np.asarray(v)[idx >= 0].min())
+    for j in np.flatnonzero((x > worst) & ~chosen):
+        blk = slice(j // block * block, (j // block + 1) * block)
+        assert np.sum(chosen[blk] & (x[blk] > x[j])) >= min(c, block), j
 
 
 def test_relaxed_topk_p_larger_than_n():

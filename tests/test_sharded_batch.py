@@ -25,8 +25,9 @@ import numpy as np
 import pytest
 
 from repro.core import batched, kpriority as kp
+from repro.kernels import default_interpret
+from repro.kernels.ops import flash_attention
 from repro.kernels.relaxed_topk import (
-    _default_interpret,
     relaxed_topk,
     relaxed_topk_batched,
     topk_select_batched,
@@ -55,16 +56,29 @@ def test_batched_kernel_matches_per_instance(backend, bn, p, c):
         np.testing.assert_array_equal(np.asarray(bi[i]), np.asarray(j))
 
 
-def test_batched_kernel_backends_agree():
-    """Pallas (interpret) and the jnp oracle share the deterministic
-    tie-break: bit-identical batched selections."""
-    x = jnp.asarray(
-        np.random.default_rng(0).integers(0, 5, (4, 700)).astype(np.float32)
-    )  # heavy ties
+def _assert_backends_agree(x):
+    x = jnp.asarray(x)
     pv, pi = relaxed_topk_batched(x, 12, c=4, block_size=128, interpret=True)
     rv, ri = relaxed_topk_batched_ref(x, 12, c=4, block_size=128)
     np.testing.assert_array_equal(np.asarray(pv), np.asarray(rv))
     np.testing.assert_array_equal(np.asarray(pi), np.asarray(ri))
+
+
+def test_batched_kernel_backends_agree():
+    """Pallas (interpret) and the jnp oracle share the deterministic
+    tie-break: bit-identical batched selections."""
+    _assert_backends_agree(
+        np.random.default_rng(0).integers(0, 5, (4, 700)).astype(np.float32)
+    )  # heavy ties
+
+
+def test_batched_kernel_backends_agree_mostly_masked():
+    """The same when most entries are -inf (the scheduler's masked scores):
+    both return distinct indices in ascending order for the -inf ties."""
+    rng = np.random.default_rng(0)
+    x = rng.integers(0, 5, (4, 700)).astype(np.float32)
+    x[rng.random(x.shape) < 0.995] = -np.inf
+    _assert_backends_agree(x)
 
 
 def test_batched_kernel_p_larger_than_n():
@@ -82,13 +96,13 @@ def test_batched_kernel_p_larger_than_n():
 # ---------------------------------------------------------------------------
 
 def test_interpret_default_routes_through_backend_logic():
-    for fn in (relaxed_topk, relaxed_topk_batched):
+    for fn in (relaxed_topk, relaxed_topk_batched, flash_attention):
         assert inspect.signature(fn).parameters["interpret"].default is None
     # on the CPU container the resolved default must be interpret mode
     # (the kernel only compiles under Mosaic); on TPU it must compile —
     # exactly topk_select's auto-backend split
     expected = jax.default_backend() != "tpu"
-    assert _default_interpret() is expected
+    assert default_interpret() is expected
     x = jax.random.normal(jax.random.PRNGKey(2), (400,))
     v_default, i_default = relaxed_topk(x, 8, c=8, block_size=128)
     v_explicit, i_explicit = relaxed_topk(
