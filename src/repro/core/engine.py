@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import kpriority as kp
 from repro.core import sssp as ss
+from repro.obs import span
 
 
 @dataclasses.dataclass
@@ -89,29 +90,35 @@ def run_sssp(
     the observed value."""
     if final is None:
         final = ss.dijkstra_ref(w)
-    wj = jnp.asarray(w)
-    fj = jnp.asarray(final)
-    state = ss.init_sssp(wj, num_places)
-    key = jax.random.PRNGKey(seed)
+    with span("sssp.prepare"):
+        wj = jnp.asarray(w)
+        fj = jnp.asarray(final)
+        state = ss.init_sssp(wj, num_places)
+        key = jax.random.PRNGKey(seed)
 
     cols = {f: [] for f in ss.PhaseStats._fields}
     phases = 0
     while phases < max_phases:
-        key, sub = jax.random.split(key)
-        state, stats = _phase(
-            state, sub, wj, fj, num_places=num_places, k=k, policy=policy,
-            arbitration=arbitration, topk_backend=topk_backend,
-        )
-        stats = jax.device_get(stats)
-        for f in ss.PhaseStats._fields:
-            cols[f].append(getattr(stats, f))
-        phases += 1
+        with span("sssp.phase"):
+            with span("sssp.dispatch"):
+                key, sub = jax.random.split(key)
+                state, stats = _phase(
+                    state, sub, wj, fj, num_places=num_places, k=k,
+                    policy=policy, arbitration=arbitration,
+                    topk_backend=topk_backend,
+                )
+            with span("sssp.readback"):
+                stats = jax.device_get(stats)
+            for f in ss.PhaseStats._fields:
+                cols[f].append(getattr(stats, f))
+            phases += 1
         if stats.active == 0 and stats.relaxed == 0:
             break
 
-    per_phase = {f: np.asarray(v) for f, v in cols.items()}
-    dist = np.asarray(jax.device_get(state.dist))
-    return _summarize_run(per_phase, dist, final, phases)
+    with span("sssp.finish"):
+        per_phase = {f: np.asarray(v) for f, v in cols.items()}
+        dist = np.asarray(jax.device_get(state.dist))
+        return _summarize_run(per_phase, dist, final, phases)
 
 
 def _summarize_run(
@@ -255,38 +262,41 @@ def run_sssp_batched(
         phase_chunk = 1 if mesh is None else 16
     if phase_chunk < 1:
         raise ValueError(f"phase_chunk must be >= 1, got {phase_chunk}")
-    ws = np.asarray(ws)
-    num_graphs = ws.shape[0]
+    num_graphs = len(ws)
     if seeds is None:
         seeds = list(range(num_graphs))
     if len(seeds) != num_graphs:
         raise ValueError(f"{len(seeds)} seeds for {num_graphs} graphs")
     if finals is None:
-        finals = np.stack([ss.dijkstra_ref(w) for w in ws])
+        finals = np.stack([ss.dijkstra_ref(np.asarray(w)) for w in ws])
 
     pad = 0
     if mesh is not None:
         from repro.core.sharded_batch import batch_axis_size
 
         pad = -num_graphs % batch_axis_size(mesh)
-    if pad:
-        n = ws.shape[1]
-        # inert padding: no edges => the source task pops once, nothing
-        # improves, the instance drains and rides along as no-op phases
-        w_inert = np.full((pad, n, n), np.inf, np.float32)
-        f_inert = np.full((pad, n), np.inf, np.float64)
-        f_inert[:, 0] = 0.0
-        ws = np.concatenate([ws, w_inert], axis=0)
-        finals = np.concatenate([finals, f_inert.astype(finals.dtype)], axis=0)
-        seeds = list(seeds) + list(range(pad))
+    # the weights go through host memory: bytes= is what crosses each way
+    with span("sssp.prepare", bytes=int(ws.nbytes)):
+        ws = np.asarray(ws)
+        if pad:
+            n = ws.shape[1]
+            # inert padding: no edges => the source task pops once, nothing
+            # improves, the instance drains and rides along as no-op phases
+            w_inert = np.full((pad, n, n), np.inf, np.float32)
+            f_inert = np.full((pad, n), np.inf, np.float64)
+            f_inert[:, 0] = 0.0
+            ws = np.concatenate([ws, w_inert], axis=0)
+            finals = np.concatenate([finals, f_inert.astype(finals.dtype)],
+                                    axis=0)
+            seeds = list(seeds) + list(range(pad))
 
-    t0 = time.time()
-    wj = jnp.asarray(ws)
-    fj = jnp.asarray(finals)
-    state = jax.vmap(
-        functools.partial(ss.init_sssp, num_places=num_places)
-    )(wj)
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+        t0 = time.perf_counter()
+        wj = jnp.asarray(ws)
+        fj = jnp.asarray(finals)
+        state = jax.vmap(
+            functools.partial(ss.init_sssp, num_places=num_places)
+        )(wj)
+        keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
 
     def phase_fn(chunk, state, keys):
         if mesh is None:
@@ -307,28 +317,33 @@ def run_sssp_batched(
         # a chunked run truncates bit-identically to an unchunked one (the
         # tail chunk costs one extra compile, and only when the cap is hit)
         chunk = min(phase_chunk, max_phases - phases)
-        state, stats, keys = phase_fn(chunk, state, keys)
-        stats = jax.device_get(stats)              # leaves [chunk, G]
-        for t in range(chunk):
-            for f in ss.PhaseStats._fields:
-                cols[f].append(getattr(stats, f)[t])
-            drained = (stats.active[t] == 0) & (stats.relaxed[t] == 0)
-            newly = (done_at < 0) & drained
-            done_at[newly] = phases
-            phases += 1
+        with span("sssp.phase"):
+            with span("sssp.dispatch"):
+                state, stats, keys = phase_fn(chunk, state, keys)
+            with span("sssp.readback"):
+                stats = jax.device_get(stats)          # leaves [chunk, G]
+            for t in range(chunk):
+                for f in ss.PhaseStats._fields:
+                    cols[f].append(getattr(stats, f)[t])
+                drained = (stats.active[t] == 0) & (stats.relaxed[t] == 0)
+                newly = (done_at < 0) & drained
+                done_at[newly] = phases
+                phases += 1
         if (done_at >= 0).all():
             break
     done_at[done_at < 0] = phases - 1   # max_phases hit: truncate at the end
 
-    dist = np.asarray(jax.device_get(state.dist))   # [G, n]
-    wall = time.time() - t0
+    with span("sssp.finish"):
+        dist = np.asarray(jax.device_get(state.dist))   # [G, n]
+        wall = time.perf_counter() - t0
 
-    runs: List[SSSPRun] = []
-    for g in range(num_graphs):
-        g_phases = int(done_at[g]) + 1
-        per_phase = {
-            f: np.asarray([row[g] for row in cols[f][:g_phases]])
-            for f in ss.PhaseStats._fields
-        }
-        runs.append(_summarize_run(per_phase, dist[g], finals[g], g_phases))
+        runs: List[SSSPRun] = []
+        for g in range(num_graphs):
+            g_phases = int(done_at[g]) + 1
+            per_phase = {
+                f: np.asarray([row[g] for row in cols[f][:g_phases]])
+                for f in ss.PhaseStats._fields
+            }
+            runs.append(_summarize_run(per_phase, dist[g], finals[g],
+                                       g_phases))
     return SSSPBatchRun(runs=runs, joint_phases=phases, wall_s=wall)

@@ -108,37 +108,44 @@ def sssp_phase(
     arbitration: str = "fused",
     topk_backend: str = "auto",
 ) -> Tuple[SSSPState, PhaseStats]:
-    """One phase: every place pops + relaxes its best visible node."""
-    k_pop, k_push = jax.random.split(key)
-    pool, res = kp.phase_pop(
-        state.pool, k_pop, num_places=num_places, k=k, policy=policy,
-        arbitration=arbitration, topk_backend=topk_backend,
-    )
-    ignored = kp.ignored_count(state.pool, res)
+    """One phase: every place pops + relaxes its best visible node. Its
+    parts carry the device scopes ``pop``, ``relax``, ``push`` and
+    ``stats`` (DESIGN.md §17)."""
+    with jax.named_scope("pop"):
+        k_pop, k_push = jax.random.split(key)
+        pool, res = kp.phase_pop(
+            state.pool, k_pop, num_places=num_places, k=k, policy=policy,
+            arbitration=arbitration, topk_backend=topk_backend,
+        )
+        ignored = kp.ignored_count(state.pool, res)
 
     # ---- relax the popped rows (Listing 5, vectorized) -----------------
-    rows = w[res.slot]                                   # [P, n]
-    cand = jnp.where(res.valid[:, None], res.prio[:, None] + rows, INF)
-    best = jnp.min(cand, axis=0)                         # [n]
-    src_place = jnp.argmin(cand, axis=0).astype(jnp.int32)
-    improved = best < state.dist
-    dist = jnp.where(improved, best, state.dist)
+    with jax.named_scope("relax"):
+        rows = w[res.slot]                               # [P, n]
+        cand = jnp.where(res.valid[:, None], res.prio[:, None] + rows, INF)
+        best = jnp.min(cand, axis=0)                     # [n]
+        src_place = jnp.argmin(cand, axis=0).astype(jnp.int32)
+        improved = best < state.dist
+        dist = jnp.where(improved, best, state.dist)
 
-    pool = kp.push(
-        pool, improved, dist, src_place, k=k, policy=policy, key=k_push
-    )
+    with jax.named_scope("push"):
+        pool = kp.push(
+            pool, improved, dist, src_place, k=k, policy=policy, key=k_push
+        )
 
-    relaxed = jnp.sum(res.valid)
-    settled = jnp.sum(res.valid & (res.prio <= final[res.slot] + SETTLED_EPS))
-    hi = jnp.max(jnp.where(res.valid, res.prio, -INF))
-    lo = jnp.min(jnp.where(res.valid, res.prio, INF))
-    h_star = jnp.where(relaxed > 0, hi - lo, 0.0)
-    stats = PhaseStats(
-        relaxed=relaxed.astype(jnp.int32),
-        settled=settled.astype(jnp.int32),
-        pushes=jnp.sum(improved).astype(jnp.int32),
-        h_star=h_star.astype(jnp.float32),
-        ignored=ignored.astype(jnp.int32),
-        active=jnp.sum(pool.active).astype(jnp.int32),
-    )
+    with jax.named_scope("stats"):
+        relaxed = jnp.sum(res.valid)
+        settled = jnp.sum(
+            res.valid & (res.prio <= final[res.slot] + SETTLED_EPS))
+        hi = jnp.max(jnp.where(res.valid, res.prio, -INF))
+        lo = jnp.min(jnp.where(res.valid, res.prio, INF))
+        h_star = jnp.where(relaxed > 0, hi - lo, 0.0)
+        stats = PhaseStats(
+            relaxed=relaxed.astype(jnp.int32),
+            settled=settled.astype(jnp.int32),
+            pushes=jnp.sum(improved).astype(jnp.int32),
+            h_star=h_star.astype(jnp.float32),
+            ignored=ignored.astype(jnp.int32),
+            active=jnp.sum(pool.active).astype(jnp.int32),
+        )
     return SSSPState(dist=dist, pool=pool), stats

@@ -50,6 +50,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.host_queue import HybridKQueue, MultiQueue
 from repro.models import decode_step, init_cache, prefill
+from repro.obs import span
 from repro.serve.config import LEGACY_KWARGS, ServeConfig
 
 
@@ -114,13 +115,17 @@ class _PlanPacker:
                 self._busy += 1
                 self._cv.notify_all()
             try:
-                pool_slot, uid = self._loop.submit_planned(
-                    frontend, qprio, req, req.tokens, req.max_new,
-                    deadline=getattr(req, "deadline", None))
-                # place_of == frontend under HYBRID; under MULTIQUEUE it is
-                # the hashed home place the fold routes by (§14.2/§16)
-                self._book.publish_wait(
-                    self._loop.place_of(pool_slot), pool_slot, qprio, uid)
+                with span("serve.pack", rid=req.rid):
+                    with span("serve.prefill"):
+                        pool_slot, uid = self._loop.submit_planned(
+                            frontend, qprio, req, req.tokens, req.max_new,
+                            deadline=getattr(req, "deadline", None))
+                    # place_of == frontend under HYBRID; under MULTIQUEUE it
+                    # is the hashed home place the fold routes by (§14.2/§16)
+                    with span("serve.publish_wait"):
+                        self._book.publish_wait(
+                            self._loop.place_of(pool_slot), pool_slot, qprio,
+                            uid)
             except BaseException as e:  # noqa: BLE001 - relayed to engine
                 with self._cv:
                     self._error = e
@@ -666,12 +671,18 @@ class ServeEngine:
 
     def step(self) -> List[Request]:
         """Admit (+ preempt) + one decode step for all active slots; returns
-        finished."""
-        if self.step_mode == "continuous":
-            self._publish_boundary()
-            return self._consume(self._fused.run_steps(1))
+        finished. Traced as the span ``serve.step`` (``repro.obs``)."""
+        with span("serve.step"):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         if self._fused is not None:
-            return self._consume(self._fused.run_steps(1))
+            if self.step_mode == "continuous":
+                with span("serve.plan"):
+                    self._publish_boundary()
+            records = self._fused.run_steps(1)
+            with span("serve.consume"):
+                return self._consume(records)
         self.clock += 1
         self._filled = set()
         self._admit()

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import kpriority as kp
+from repro.obs import span
 from repro.serve import streaming
 from repro.serve.streaming import AdmissionBuffer, PlanSlot, fold
 
@@ -355,15 +356,18 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
             # publish-on-k counters, spy refs — bit-identical to the
             # unmasked program); only decode + preempt arbitration are
             # gated on the step having any work
-            pool, _ = fold(c.pool, buf, k=k)
+            with jax.named_scope("fold"):
+                pool, _ = fold(c.pool, buf, k=k)
             if storage == "klsm":
                 # re-derive the level store from the freshly folded pool,
                 # then pop through the level-front probe (§15): one fold
                 # publishes ≤ per-step buffer width + K entries per place
                 bc = buf.prio.shape[-1] + max(k, 1)
-                store = kp.klsm_sync(pool, c.store, batch_cap=bc)
-                pool, store, res = kp.klsm_pop_fill(
-                    pool, store, c.slot_req < 0, places_vec)
+                with jax.named_scope("klsm_sync"):
+                    store = kp.klsm_sync(pool, c.store, batch_cap=bc)
+                with jax.named_scope("pop_fill"):
+                    pool, store, res = kp.klsm_pop_fill(
+                        pool, store, c.slot_req < 0, places_vec)
                 mq_pops, pop_aborts = c.mq_pops, c.pop_aborts
             elif policy == "multiqueue":
                 # miss-tolerant sampled fill (§16): attempts — hits AND
@@ -372,13 +376,15 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
                 # which is what keeps the c=2 draws (hence admission order)
                 # bit-identical across all four planes
                 store = c.store
-                pool, mq_pops, res, ab = kp.stream_pop_fill_mq(
-                    pool, c.slot_req < 0, c.mq_pops)
-                pop_aborts = c.pop_aborts + ab
+                with jax.named_scope("pop_fill"):
+                    pool, mq_pops, res, ab = kp.stream_pop_fill_mq(
+                        pool, c.slot_req < 0, c.mq_pops)
+                    pop_aborts = c.pop_aborts + ab
             else:
                 store = c.store
-                pool, res = kp.stream_pop_fill(
-                    pool, c.slot_req < 0, places_vec)
+                with jax.named_scope("pop_fill"):
+                    pool, res = kp.stream_pop_fill(
+                        pool, c.slot_req < 0, places_vec)
                 mq_pops, pop_aborts = c.mq_pops, c.pop_aborts
             got = res.valid                              # bool[S]
             live = jnp.any(got) | jnp.any(c.slot_req >= 0)
@@ -387,20 +393,22 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
             clock = c.clock + 1
 
             def live_step(c):
-                ps = jnp.where(got, res.slot, 0)         # i32[S]
-                rows = c.staging.row[ps]                 # i32[S]
-                cur_tok = jnp.where(got, c.staging.tok[rows], c.cur_tok)
-                pos = jnp.where(got, c.staging.pos[rows], c.pos)
-                out_len = jnp.where(got, c.staging.out_len[rows], c.out_len)
-                budget = jnp.where(got, c.staging.budget[rows], c.budget)
-                slot_req = jnp.where(got, ps, c.slot_req)
-                slot_prio = jnp.where(got, res.prio, c.slot_prio)
-                slot_uid = jnp.where(got, pool.seq[ps], c.slot_uid)
-                slot_creator = jnp.where(got, pool.creator[ps],
-                                         c.slot_creator)
-                slot_deadline = jnp.where(got, c.staging.deadline[rows],
-                                          c.slot_deadline)
-                caches = splice_in(c.caches, c.staged_caches, rows, got)
+                with jax.named_scope("splice_in"):
+                    ps = jnp.where(got, res.slot, 0)         # i32[S]
+                    rows = c.staging.row[ps]                 # i32[S]
+                    cur_tok = jnp.where(got, c.staging.tok[rows], c.cur_tok)
+                    pos = jnp.where(got, c.staging.pos[rows], c.pos)
+                    out_len = jnp.where(got, c.staging.out_len[rows],
+                                        c.out_len)
+                    budget = jnp.where(got, c.staging.budget[rows], c.budget)
+                    slot_req = jnp.where(got, ps, c.slot_req)
+                    slot_prio = jnp.where(got, res.prio, c.slot_prio)
+                    slot_uid = jnp.where(got, pool.seq[ps], c.slot_uid)
+                    slot_creator = jnp.where(got, pool.creator[ps],
+                                             c.slot_creator)
+                    slot_deadline = jnp.where(got, c.staging.deadline[rows],
+                                              c.slot_deadline)
+                    caches = splice_in(c.caches, c.staged_caches, rows, got)
                 staging, staged_caches = c.staging, c.staged_caches
 
                 store_out = store
@@ -410,8 +418,9 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
                           slot_uid, slot_creator, slot_deadline, clock, got)
                     if storage == "klsm":
                         st = st + (store,)
-                    st, (pre_slot, pre_vps, pre_ps) = jax.lax.scan(
-                        preempt_round, st, None, length=n_rounds)
+                    with jax.named_scope("preempt"):
+                        st, (pre_slot, pre_vps, pre_ps) = jax.lax.scan(
+                            preempt_round, st, None, length=n_rounds)
                     if storage == "klsm":
                         st, store_out = st[:-1], st[-1]
                     (pool_out, caches, staging, staged_caches, cur_tok,
@@ -422,8 +431,9 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
                     empty = jnp.zeros((0,), jnp.int32)
                     pre_slot = pre_vps = pre_ps = empty
 
-                logits, caches = decode_fn(params, caches, cur_tok, pos)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("decode"):
+                    logits, caches = decode_fn(params, caches, cur_tok, pos)
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 active = slot_req >= 0
                 pos = jnp.where(active, pos + 1, pos)
                 cur_tok = jnp.where(active, nxt, cur_tok)
@@ -468,24 +478,27 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
             # at this chunk's first step — then clear it and flip, so the
             # host packs the next plan into the other slot while this
             # chunk runs
-            sel = carry.plan_sel
-            plan = carry.plan
-            ready = AdmissionBuffer(
-                prio=plan.prio[sel], slot=plan.slot[sel],
-                arrival=plan.arrival[sel], count=plan.count[sel])
-            pool, _ = fold(carry.pool, ready, k=k)
-            if storage == "klsm":
-                # sync HERE, not at the scan's first step: the boundary fold
-                # can publish a full plan row (+ carried unpublished) per
-                # place, more than the per-step batch_cap budgets for
-                carry = carry._replace(store=kp.klsm_sync(
-                    pool, carry.store,
-                    batch_cap=ready.prio.shape[-1] + max(k, 1)))
-            cleared = AdmissionBuffer(
-                prio=plan.prio.at[sel].set(jnp.inf),
-                slot=plan.slot.at[sel].set(-1),
-                arrival=plan.arrival.at[sel].set(0),
-                count=plan.count.at[sel].set(0))
+            with jax.named_scope("plan_fold"):
+                sel = carry.plan_sel
+                plan = carry.plan
+                ready = AdmissionBuffer(
+                    prio=plan.prio[sel], slot=plan.slot[sel],
+                    arrival=plan.arrival[sel], count=plan.count[sel])
+                pool, _ = fold(carry.pool, ready, k=k)
+                if storage == "klsm":
+                    # sync HERE, not at the scan's first step: the boundary
+                    # fold can publish a full plan row (+ carried
+                    # unpublished) per place, more than the per-step
+                    # batch_cap budgets for
+                    with jax.named_scope("klsm_sync"):
+                        carry = carry._replace(store=kp.klsm_sync(
+                            pool, carry.store,
+                            batch_cap=ready.prio.shape[-1] + max(k, 1)))
+                cleared = AdmissionBuffer(
+                    prio=plan.prio.at[sel].set(jnp.inf),
+                    slot=plan.slot.at[sel].set(-1),
+                    arrival=plan.arrival.at[sel].set(0),
+                    count=plan.count.at[sel].set(0))
             carry = carry._replace(pool=pool, plan=cleared,
                                    plan_sel=1 - sel)
         return jax.lax.scan(one_step, carry, bufs)
@@ -1038,10 +1051,21 @@ class FusedServeLoop:
         :class:`StepRecord` per step, in engine event order (admissions in
         decode-slot order, then preemption rounds, then decode tokens, then
         completions — exactly the eager ``ServeEngine.step`` sequence)."""
-        bufs, counts = self._pack_bufs(n)
-        fn = self._chunk_fn(n)
-        self.carry, ev = fn(self.params, self.carry, bufs)
-        self._count()
+        with span("serve.dispatch"):
+            bufs, counts = self._pack_bufs(n)
+            fn = self._chunk_fn(n)
+            self.carry, ev = fn(self.params, self.carry, bufs)
+            self._count()
+        with span("serve.readback"):
+            ev = StepEvents(*(np.asarray(leaf) for leaf in ev))
+        with span("serve.replay"):
+            return self._replay(n, counts, ev)
+
+    def _replay(self, n: int, counts: np.ndarray,
+                ev: StepEvents) -> List[StepRecord]:
+        """Replay a chunk's events, read back as numpy arrays, into the host
+        mirrors and one :class:`StepRecord` per step."""
+        admit, token, active, done, live, pre_slot, pre_vps, pre_ps = ev
         if self.continuous:
             # the chunk folded (and cleared) device plan slot _hsel and
             # flipped plan_sel — mirror both host-side: publish-on-k
@@ -1053,14 +1077,6 @@ class FusedServeLoop:
                 for pl in range(self.frontends):
                     u = self._unpub[pl] + int(pc[pl])
                     self._unpub[pl] = 0 if self.k == 0 else u % self.k
-        admit = np.asarray(ev.admit)
-        token = np.asarray(ev.token)
-        active = np.asarray(ev.active)
-        done = np.asarray(ev.done)
-        live = np.asarray(ev.live)
-        pre_slot = np.asarray(ev.pre_slot)
-        pre_vps = np.asarray(ev.pre_vps)
-        pre_ps = np.asarray(ev.pre_ps)
         self.work_steps += int(live.sum())
         self.noop_steps += n - int(live.sum())
         retain = self.preemption == "margin"
