@@ -333,9 +333,29 @@ def backbone(params, cfg: ModelConfig, x, pos, mode: str, caches=None):
                 acc = dict(acc); acc[mk] = acc[mk] + mv
             return (x, acc), ncache
 
+        def body_in_place(carry, p_layer):
+            (x, acc), cache, i = carry
+            cache_layer = jax.tree.map(
+                lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False),
+                cache)
+            (x, acc), ncache = body((x, acc), (p_layer, cache_layer))
+            cache = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+                cache, ncache)
+            return ((x, acc), cache, i + 1), None
+
         acc0 = {"router_dropped": jnp.zeros((), F32)} if has_moe else {}
-        xs = (seg_params,) if caches is None else (seg_params, seg_cache)
-        (x, acc0), seg_cache_out = jax.lax.scan(body, (x, acc0), xs)
+        if mode == "decode":
+            # the caches ride the carry and each layer's is written back in
+            # place: as scan outputs they would land in a fresh buffer, and
+            # a caller that keeps its caches in a loop carry (the fused
+            # serving step) would copy the whole cache back every step
+            ((x, acc0), seg_cache_out, _), _ = jax.lax.scan(
+                body_in_place, ((x, acc0), seg_cache, jnp.int32(0)),
+                seg_params)
+        else:
+            xs = (seg_params,) if caches is None else (seg_params, seg_cache)
+            (x, acc0), seg_cache_out = jax.lax.scan(body, (x, acc0), xs)
         metrics = _merge_metrics(metrics, acc0)
         new_caches.append(seg_cache_out)
 

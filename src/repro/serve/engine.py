@@ -753,3 +753,13 @@ class ServeEngine:
         admission plane — the metric ``benchmarks --only fused_step`` tracks
         (DESIGN.md §10 dispatch-count math)."""
         return self._dispatches + getattr(self.queue, "dispatches", 0)
+
+    @property
+    def splices(self) -> int:
+        """Decode slots the fused step programs spliced a staged request
+        into so far (``FusedServeLoop.splices``): one per admission from the
+        queue's fill, so the ``splice_in`` scope's device time over it is
+        the time of one splice. A preempt round's challenger is written
+        into its slot in place too, but is not counted. 0 on the eager step
+        modes, which splice on the host's side of the step."""
+        return self._fused.splices if self._fused is not None else 0

@@ -15,9 +15,11 @@ traced program: a :class:`FusedServeLoop` step is
   2. **admit** — :func:`repro.core.kpriority.stream_pop_fill`: the engine's
      sequential fill of empty decode slots (stop at the first failed pop)
      as a ``lax.scan`` threading the :class:`PoolState` through its carry,
-  3. **splice** — admitted slots gather their resume state (next token,
+  3. **splice** — admitted slots take their resume state (next token,
      position, emitted count, token budget, KV cache) from a device-resident
-     staging area, through a pool-slot → staging-row indirection,
+     staging area, through a pool-slot → staging-row indirection; the KV
+     cache moves by one in-place write per admitted slot
+     (:func:`splice_in`),
   4. **preempt** (``preemption="margin"``, §11) — up to ``slots`` rounds of
      :func:`repro.core.kpriority.preempt_plan`: whenever the queue's visible
      front beats the worst running slot by ``margin``, the victim's decode
@@ -158,6 +160,34 @@ class _Arrival(NamedTuple):
     uid: int        # global arrival index
 
 
+def splice_in(caches, staged_caches, rows, mask):
+    """Write staged row ``rows[s]`` into decode-slot column ``s`` of every
+    cache leaf, for each slot ``s`` where ``mask[s]`` (DESIGN.md §10.1).
+
+    One in-place write per admitted slot: a loop over the admitted slots
+    alone, so a step pays O(admitted × one slot's cache) and a step that
+    admits nothing moves no cache bytes. Bit-identical to gathering every
+    slot's row and selecting it over the decode caches by ``mask``: the same
+    values, cast to the decode dtype the same way, and untouched columns
+    unchanged. (A ``lax.cond`` per slot is no cheaper: XLA then gives the
+    last cond's result the decode loop's layout, a copy of the whole
+    cache.)"""
+    admitted = jnp.flatnonzero(mask, size=mask.shape[0], fill_value=0)
+
+    def write(i, caches):
+        s = admitted[i]
+
+        def one(full, stage):
+            row = jax.lax.dynamic_slice_in_dim(stage, rows[s], 1, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(
+                full, row.astype(full.dtype), s, axis=1)
+
+        return jax.tree.map(one, caches, staged_caches)
+
+    return jax.lax.fori_loop(0, jnp.sum(mask, dtype=jnp.int32), write,
+                             caches)
+
+
 def build_chunk_fn(decode_fn: Callable, *, k: int, frontends: int,
                    slots: int, max_len: int, n: int,
                    preempt: bool = False, margin: float = 0.0,
@@ -227,15 +257,6 @@ def _build_chunk_impl(decode_fn: Callable, *, k: int, frontends: int,
     # after the victim's re-push — ≤ max(k, 1) newly published entries for
     # one place — before popping the challenger through the heads, exactly
     # the eager plane's peek → repush(+sync) → pop sequence (DESIGN.md §16).
-
-    def splice_in(caches, staged_caches, rows, mask):
-        """Gather staged rows into decode-slot columns where ``mask``."""
-        def one(full, stage):
-            g = jnp.take(stage, rows, axis=1)            # [lead, S, ...]
-            m = mask.reshape((1, -1) + (1,) * (full.ndim - 2))
-            return jnp.where(m, g.astype(full.dtype), full)
-
-        return jax.tree.map(one, caches, staged_caches)
 
     def preempt_round(st, _):
         # under storage="klsm" the level store rides the round carry as a
@@ -676,6 +697,10 @@ class FusedServeLoop:
         self.clock = 0
         self.work_steps = 0            # steps that did decode/preempt work
         self.noop_steps = 0            # dead-masked steps (ev.live False)
+        # slot splices the step programs performed: phase-1 admissions
+        # only (a preempt round's challenger is written in place too, but
+        # is not counted); summed from the events, no device cost
+        self.splices = 0
         r = self.staging_rows
         staging = Staging(
             tok=jnp.zeros((r,), jnp.int32),
@@ -1079,6 +1104,7 @@ class FusedServeLoop:
                     self._unpub[pl] = 0 if self.k == 0 else u % self.k
         self.work_steps += int(live.sum())
         self.noop_steps += n - int(live.sum())
+        self.splices += int((admit >= 0).sum())
         retain = self.preemption == "margin"
         records: List[StepRecord] = []
         for t in range(n):

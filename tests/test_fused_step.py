@@ -616,6 +616,137 @@ def test_engine_fused_caches_stay_live():
     assert leaves and np.asarray(leaves[0]) is not None
 
 
+# ---------------------------------------------------------------------------
+# splice_in: one in-place write per admitted slot
+# ---------------------------------------------------------------------------
+
+def _splice_gather_where(caches, staged_caches, rows, mask):
+    """The masked-gather splice ``splice_in`` replaced, kept as its oracle:
+    gather every slot's staged row, select it over the decode caches."""
+    def one(full, stage):
+        g = jnp.take(stage, rows, axis=1)
+        m = mask.reshape((1, -1) + (1,) * (full.ndim - 2))
+        return jnp.where(m, g.astype(full.dtype), full)
+
+    return jax.tree.map(one, caches, staged_caches)
+
+
+def _bits(tree):
+    return [np.asarray(x).tobytes() for x in jax.tree.leaves(tree)]
+
+
+SPLICE_MASKS = {
+    "none": [False] * 6,
+    "all": [True] * 6,
+    "alternate": [True, False] * 3,
+    "random": list(np.random.default_rng(5).random(6) < 0.5),
+}
+
+
+@pytest.mark.parametrize("mask", sorted(SPLICE_MASKS))
+@pytest.mark.parametrize("seed", [0, 1])
+def test_splice_in_matches_masked_gather(mask, seed):
+    """``splice_in`` leaves caches bit-identical to the masked gather, with
+    staged leaves wider than the decode ones (the cast) and rows repeated,
+    masked off or not."""
+    from repro.serve.fused_step import splice_in
+
+    slots, rows_n = 6, 5
+    rng = np.random.default_rng(seed)
+
+    def arr(shape, dtype):
+        return jnp.asarray(rng.standard_normal(shape) * 3.7, dtype)
+
+    caches = {"k": arr((3, slots, 2, 7), jnp.bfloat16),
+              "v": (arr((3, slots, 4), jnp.float32),)}
+    staged = {"k": arr((3, rows_n, 2, 7), jnp.float32),
+              "v": (arr((3, rows_n, 4), jnp.float32),)}
+    rows = jnp.asarray(rng.integers(0, rows_n, slots), jnp.int32)
+    rows = rows.at[1].set(rows[0])                  # a repeated row
+    m = jnp.asarray(SPLICE_MASKS[mask])
+    want = jax.jit(_splice_gather_where)(caches, staged, rows, m)
+    got = jax.jit(splice_in)(caches, staged, rows, m)
+    assert _bits(got) == _bits(want)
+    if mask == "none":
+        assert _bits(got) == _bits(caches)
+
+
+def _eqns(jaxpr, in_scope=False, scope="splice_in"):
+    """Every equation of ``jaxpr`` and its sub-jaxprs, with whether it was
+    traced under the named scope ``scope`` (inherited by a loop's body)."""
+    for eqn in jaxpr.eqns:
+        inside = in_scope or scope in str(eqn.source_info.name_stack).split(
+            "/")
+        yield eqn, inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inside, scope)
+
+
+@pytest.mark.parametrize("preemption", ["off", "margin"])
+def test_splice_moves_no_whole_cache(preemption):
+    """Structure guard: nothing traced under ``splice_in`` gathers or selects
+    an array of a whole decode-cache leaf's shape — only the in-place
+    update (and the loop that carries it) has that shape."""
+    from repro.serve.fused_step import (FusedServeLoop, _build_chunk_impl,
+                                        toy_decode_fn, toy_prefill_fn)
+
+    slots = 3
+    leaf = (2, slots, 3, 4)
+    loop = FusedServeLoop(
+        slots=slots, frontends=2, k=1, max_len=64, capacity=16,
+        caches={"kv": jnp.zeros(leaf, jnp.bfloat16)},
+        decode_fn=toy_decode_fn, prefill_fn=toy_prefill_fn,
+        preemption=preemption, margin=0.5, continuous=True)
+    fn = _build_chunk_impl(
+        loop.decode_fn, k=loop.k, frontends=loop.frontends, slots=slots,
+        max_len=loop.max_len, n=2, preempt=preemption == "margin",
+        margin=loop.margin, rounds=loop.rounds, continuous=True)
+    bufs, _ = loop._pack_bufs(2)
+    jaxpr = jax.make_jaxpr(fn)(loop.params, loop.carry, bufs).jaxpr
+    spliced = [(e.primitive.name, tuple(v.aval.shape))
+               for e, inside in _eqns(jaxpr) if inside for v in e.outvars]
+    assert spliced, "no equation traced under splice_in"
+    whole = {p for p, shape in spliced if shape == leaf}
+    assert whole == {"dynamic_update_slice", "while"}, whole
+
+
+@pytest.mark.parametrize("step,preemption", [
+    ("fused", "off"), ("continuous", "off"), ("fused", "margin")])
+def test_engine_splices_count_admissions(step, preemption):
+    """``ServeEngine.splices`` counts one splice per admission from the
+    queue's fill; a preempt round's challenger is seated without one."""
+    from repro.configs import get_reduced
+    from repro.models import materialize, model_p
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg = get_reduced("qwen3_1_7b")
+    params = materialize(jax.random.PRNGKey(0), model_p(cfg))
+    rng = np.random.default_rng(4)
+    eng = ServeEngine(cfg, params, slots=2, max_len=48, frontends=2, k=1,
+                      config=ServeConfig(step=step, step_chunk=2,
+                                         preemption=preemption,
+                                         preempt_margin=0.5))
+    for rid in range(2):                      # long, low priority
+        eng.submit(Request(rid=rid, tokens=rng.integers(
+            0, cfg.vocab_size, 5).astype(np.int32), max_new=7,
+            priority=9.0), frontend=rid % 2)
+    eng.wait_packed()
+    eng.step()
+    eng.step()
+    for rid in range(2, 5):                   # short, high priority
+        eng.submit(Request(rid=rid, tokens=rng.integers(
+            0, cfg.vocab_size, 4).astype(np.int32), max_new=3,
+            priority=float(rid)), frontend=rid % 2)
+    eng.wait_packed()
+    assert len(eng.run()) == 5
+    assert len(eng.admission_log) >= 5
+    if preemption == "off":
+        assert eng.preempt_log == []
+    else:
+        assert eng.preempt_log, "no preemption fired; strengthen the trace"
+    assert eng.splices == len(eng.admission_log) - len(eng.preempt_log)
+
+
 def test_fused_selftest_8_devices():
     """Acceptance pin: fused step == host oracle == eager device plane under
     the 8-device composed (batch × data × model) production-style mesh —

@@ -144,3 +144,51 @@ def test_sharded_sssp_chunk_compiles_on_four_chips(topo):
         _shape((graphs, SSSP_N), jnp.float32, sharded),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("preemption", ["off", "margin"])
+def test_serving_step_keeps_caches_in_place(one_chip, preemption):
+    """The fused serving step at Qwen3-1.7B's widths, in the shape the
+    serving benchmark runs (8 slots of 1024 positions, 40 staging rows, one
+    step a dispatch), keeps its KV caches in place: no temporary as large
+    as one slot's column of the caches, and no copy of a whole cache leaf.
+    The splice writes only the admitted slots' columns, and decode updates
+    the carried caches in place."""
+    import re
+
+    from repro.configs.qwen3_1_7b import CONFIG
+    from repro.models import init_cache, materialize, model_p
+    from repro.serve.engine import _fused_model_fns
+    from repro.serve.fused_step import FusedServeLoop, _build_chunk_impl
+    from repro.serve.streaming import AdmissionBuffer
+
+    slots, max_len, frontends, cap = 8, 1024, 2, 64
+    decode_fn, prefill_fn = _fused_model_fns(CONFIG, max_len)
+    loop_kw = dict(slots=slots, frontends=frontends, k=4, max_len=max_len,
+                   capacity=4096, buffer_cap=cap, staging_rows=40,
+                   decode_fn=decode_fn, prefill_fn=prefill_fn,
+                   preemption=preemption, margin=0.5, continuous=True)
+    carry = jax.eval_shape(lambda: FusedServeLoop(
+        caches=init_cache(CONFIG, slots, max_len), **loop_kw).carry)
+    params = jax.eval_shape(
+        lambda: materialize(jax.random.PRNGKey(0), model_p(CONFIG)))
+    bufs = AdmissionBuffer(
+        prio=jax.ShapeDtypeStruct((1, frontends, cap), jnp.float32),
+        slot=jax.ShapeDtypeStruct((1, frontends, cap), jnp.int32),
+        arrival=jax.ShapeDtypeStruct((1, frontends, cap), jnp.int32),
+        count=jax.ShapeDtypeStruct((1, frontends), jnp.int32))
+    fn = _build_chunk_impl(
+        decode_fn, k=4, frontends=frontends, slots=slots, max_len=max_len,
+        n=1, preempt=preemption == "margin", margin=0.5,
+        rounds=slots if preemption == "margin" else 0, continuous=True)
+    on_chip = functools.partial(
+        jax.tree.map, lambda a: _shape(a.shape, a.dtype, one_chip))
+    compiled = fn.lower(on_chip(params), on_chip(carry),
+                        on_chip(bufs)).compile()
+    leaves = jax.tree.leaves(carry.caches)
+    column = max(a.dtype.itemsize * a.size // slots for a in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < column
+    shapes = {",".join(map(str, a.shape)) for a in leaves}
+    copies = re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} copy\(",
+                        compiled.as_text())
+    assert not shapes & set(copies), copies
