@@ -13,7 +13,8 @@ embedding matrix. The forward runs in float32 with every matrix product at
 them: ``embed``, ``final_norm`` and one segment of the layer stack, each
 matrix stored ``[in, out]`` with the gate and up projections side by side
 in ``mlp.wi``. Both the program and this reference get these weights; the
-reference takes nothing from the program.
+reference takes nothing from the program. The model FLOPs that
+``serve_mfu`` counts are ``chipbench/flops.py``'s dense GQA decoder.
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import flops
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
@@ -164,3 +167,7 @@ def token_gaps(params, tokens, cfg_items, control: bool):
 def cfg_items(cfg: dict) -> tuple:
     return tuple(sorted((k, v) for k, v in cfg.items()
                         if isinstance(v, (int, float, str, bool))))
+
+
+flops_per_token = flops.transformer_flops_per_token
+prefill_flops = flops.prefill_flops

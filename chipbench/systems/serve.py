@@ -3,8 +3,10 @@
 The configuration is the model (its published config.json keys) and the
 engine (``serve``: slots, max_len, frontends, k and the ``ServeConfig``
 fields); the mix may set further ``ServeConfig`` fields under
-``serve_config`` (preemption). Weights are random, made on the device from
-the seed in their serving dtypes.
+``serve_config`` (preemption). Its ``model_type`` picks the model's files
+(``chipbench/spec.py``): the program's ``ModelConfig``, the reference, the
+weights and the FLOP counts; nothing here names a model. Weights are
+random, made on the device from the seed in their serving dtypes.
 
 The client side is the harness: each request is due at a wall-clock time
 and is submitted once due and once a prefill staging row is free (a submit
@@ -24,9 +26,8 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
-from chipbench import flops, traffic
+from chipbench import spec, traffic
 from chipbench.common import Check, Outcome, percentile, seed_words
-from chipbench.reference import qwen3
 from chipbench.tracing import span
 
 DRAIN_S = 60.0            # how long past the window a due request may take
@@ -55,24 +56,6 @@ class State:
     pass
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a Qwen3 config.json."""
-    from repro.configs.base import ModelConfig
-
-    if cfg["model_type"] != "qwen3":
-        raise ValueError(f"no mapping for model_type {cfg['model_type']!r}")
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        qkv_bias=cfg["attention_bias"], qk_norm=True,
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=cfg["tie_word_embeddings"],
-        norm_eps=cfg["rms_norm_eps"])
-
-
 def _engine(st: State):
     from repro.serve.config import ServeConfig
     from repro.serve.engine import ServeEngine
@@ -96,11 +79,13 @@ def setup(ctx) -> State:
     st.cfg, st.serve = cfg, cfg["serve"]
     st.serve_overrides = mix.get("serve_config", {})
     st.retain = st.serve_overrides.get("preemption", "off") == "margin"
-    st.mc = model_config(cfg)
+    st.model = spec.model_files(cfg["model_type"], cfg[spec.ROOT_KEY])
+    st.mc = st.model.adapter.model_config(cfg)
     want = jax.tree.structure(model_p(st.mc), is_leaf=lambda x: isinstance(x, P))
     t0 = time.perf_counter()
     with span("make_weights"):
-        st.params = qwen3.make_weights(int(seed_words(ctx.seed, 2)[0]), cfg)
+        st.params = st.model.reference.make_weights(
+            int(seed_words(ctx.seed, 2)[0]), cfg)
         jax.block_until_ready(st.params)
     if jax.tree.structure(st.params) != want:
         raise ValueError("the weight layout differs from the model's")
@@ -159,6 +144,8 @@ def window(ctx, st: State) -> Outcome:
     from repro.serve.engine import Request
 
     eng, arr = st.eng, st.arrivals
+    prefill_flops = st.model.reference.prefill_flops
+    flops_per_token = st.model.reference.flops_per_token
     t0 = time.perf_counter()
     t_end = st.t_end = t0 + ctx.seconds
     recs = [Rec(due=t0 + a.due_s, arrival=a, tokens=st.prompts[i])
@@ -187,7 +174,7 @@ def window(ctx, st: State) -> Outcome:
             r.submit_t = time.perf_counter()
             inflight[i] = r
             if r.submit_t < t_end:
-                flop_in += flops.prefill_flops(st.cfg, a.prompt_len)
+                flop_in += prefill_flops(st.cfg, a.prompt_len)
         if not inflight:
             if nxt >= len(arr) and not client:
                 break
@@ -212,7 +199,7 @@ def window(ctx, st: State) -> Outcome:
                     tokens_in += n - r.n
                     # token i >= 1 was decoded at context prompt_len + i
                     for j in range(max(r.n, 1), n):
-                        flop_in += flops.transformer_flops_per_token(
+                        flop_in += flops_per_token(
                             st.cfg, r.arrival.prompt_len + j)
                 r.n, r.last_t = n, t
         for req in done:
@@ -292,13 +279,14 @@ def gaps(st: State, recs: List[Rec], control: bool = False):
     """Widest gap by which a served token's reference logit lies below the
     reference's best, over ``recs`` (and the same for the token that the
     control puts first at each of those positions); and the token count."""
-    items = qwen3.cfg_items(st.cfg)
+    ref = st.model.reference
+    items = ref.cfg_items(st.cfg)
     served_worst, control_worst, count = 0.0, 0.0, 0
     for r in recs:
         seq = np.concatenate([r.tokens, np.asarray(r.req.out, np.int32)])
         pad = np.zeros(st.serve["max_len"], np.int32)
         pad[: len(seq)] = seq
-        s, c = qwen3.token_gaps(st.params, pad, items, control)
+        s, c = ref.token_gaps(st.params, pad, items, control)
         # served token i sits at prompt_len + i, predicted at one before
         lo, hi = len(r.tokens) - 1, len(seq) - 1
         served_worst = max(served_worst, float(np.asarray(s)[lo:hi].max()))

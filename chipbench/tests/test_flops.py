@@ -1,5 +1,9 @@
 """The operation and byte counts against hand counts."""
-from chipbench import flops
+import json
+
+import pytest
+
+from chipbench import flops, spec
 
 TINY = {"hidden_size": 4, "num_attention_heads": 2, "num_key_value_heads": 1,
         "head_dim": 2, "intermediate_size": 3, "vocab_size": 5,
@@ -30,6 +34,20 @@ def test_qwen3_1_7b_weights_per_token():
     # 1.72 G weights (the published 1.7 B), 3.4 GFLOP a token at no context
     per_tok = flops.transformer_flops_per_token(cfg, 0)
     assert per_tok == 2 * (28 * 50_331_648 + 2048 * 151936)
+
+
+@pytest.mark.parametrize("context,prompt_len", [
+    (0, 1), (1, 8), (129, 128), (767, 256), (1023, 512)])
+def test_qwen3_model_file_counts_are_the_dense_decoders(context, prompt_len):
+    """``serve_mfu`` counts the same FLOPs through the model's file as
+    through ``flops.py``, bit for bit, at the cell's published widths."""
+    cfg = json.loads(
+        (spec.HERE / "configs" / "qwen3_1_7b_s8.json").read_text())
+    ref = spec.model_files(cfg["model_type"], spec.HERE.parent).reference
+    assert (ref.flops_per_token(cfg, context)
+            == flops.transformer_flops_per_token(cfg, context))
+    assert (ref.prefill_flops(cfg, prompt_len)
+            == flops.prefill_flops(cfg, prompt_len))
 
 
 def test_selection_and_phase_counts_by_hand():
